@@ -48,13 +48,14 @@ def _time_mode(name: str, cfg, ds, fmt: str, *, steps: int,
     from repro.core import trainer as T
 
     state, _, opt = T.init_state(jax.random.key(seed), cfg, pool_size=2048)
-    step_fn = T.make_train_step(cfg, opt, features=features, donate=donate)
+    step_fn = T.make_train_step(cfg, opt, donate=donate)
     per_type = {et: batch_per_type for et in ("uu", "ui", "ii")}
 
     def one(t):
         batch = jax.tree.map(jnp.asarray,
                              ds.sample_batch(t, seed, per_type, format=fmt))
-        return step_fn(state_box[0], batch, jax.random.key(1000 + t))
+        return step_fn(state_box[0], batch, jax.random.key(1000 + t),
+                       features)
 
     # warmup pass over the *same* (seed, step) range the measurement
     # will replay: every pack-size bucket the measured pass can hit is

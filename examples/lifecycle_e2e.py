@@ -28,6 +28,7 @@ from repro.core.graph_builder import EngagementLog, build_graph
 from repro.data.edge_dataset import build_neighbor_tables
 from repro.data.synthetic import make_world
 from repro.lifecycle import LifecycleConfig, LifecycleRuntime
+from repro.compile_cache import enable_compile_cache
 
 
 def main(snapshot_dir="/tmp/rankgraph2_snapshots"):
@@ -130,4 +131,5 @@ def main(snapshot_dir="/tmp/rankgraph2_snapshots"):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

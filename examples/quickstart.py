@@ -9,6 +9,7 @@ from repro.core import evaluation as EV
 from repro.core.pipeline import run_pipeline
 from repro.core.serving import ClusterQueueStore
 from repro.data.synthetic import make_world
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -41,4 +42,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

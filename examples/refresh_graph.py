@@ -16,6 +16,7 @@ from repro.core.graph_builder import EngagementLog, build_graph
 from repro.data.edge_dataset import build_neighbor_tables, \
     incremental_refresh
 from repro.data.synthetic import make_world
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -73,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ from repro.core.pipeline import run_pipeline
 from repro.core.serving import (ClusterQueueStore, ServingCostModel,
                                 build_i2i_knn, u2i2i_retrieve_batch)
 from repro.data.synthetic import make_world
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -87,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ from repro.core.graph_builder import build_graph
 from repro.data.edge_dataset import (EdgeDataset, Prefetcher,
                                      build_neighbor_tables)
 from repro.data.synthetic import make_world
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -65,9 +66,8 @@ def main():
     ds = build(86400.0)
     state, specs, optimizer = T.init_state(jax.random.key(0), cfg,
                                            pool_size=4096)
-    step_fn = T.make_train_step(
-        cfg, optimizer,
-        features=T.make_feature_store(world.user_feat, world.item_feat))
+    step_fn = T.make_train_step(cfg, optimizer)
+    feats = T.make_feature_store(world.user_feat, world.item_feat)
 
     ck = Checkpointer(args.ckpt_dir, keep=3)
     start = 0
@@ -99,7 +99,7 @@ def main():
                                                   start_step=t), depth=2)
             print(f"[{t}] graph rebuilt in {ds.g.build_seconds:.1f}s")
         batch = jax.tree.map(jnp.asarray, next(prefetch))
-        state, m = step_fn(state, batch, jax.random.key(7000 + t))
+        state, m = step_fn(state, batch, jax.random.key(7000 + t), feats)
         if preempted["flag"]:
             ck.save(int(state.step), state,
                     metadata={"data_seed": 0, "preempted": True,
@@ -134,4 +134,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -128,7 +128,9 @@ class _Evaluator:
 
     def eval_shape(self, node: ast.AST) -> Tuple[int, ...]:
         if isinstance(node, (ast.Tuple, ast.List)):
-            return tuple(self.eval(e) for e in node.elts)
+            # a ``None`` block dim is squeezed: one element along it
+            return tuple(1 if isinstance(e, ast.Constant) and e.value is None
+                         else self.eval(e) for e in node.elts)
         raise _Unresolved(ast.unparse(node))
 
 
@@ -153,6 +155,23 @@ def _scrape_param_defaults(tree: ast.Module) -> Dict[str, int]:
                             dflt.value, bool):
                     out[arg.arg] = max(out.get(arg.arg, 0), dflt.value)
     return out
+
+
+def _scrape_module_ints(tree: ast.Module) -> Dict[str, int]:
+    """Module-level integer constants (``_ROWS = 8``)."""
+    out: Dict[str, int] = {}
+    for node in tree.body:
+        if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                and isinstance(node.targets[0], ast.Name)
+                and isinstance(node.value, ast.Constant)
+                and isinstance(node.value.value, int)
+                and not isinstance(node.value.value, bool)):
+            out[node.targets[0].id] = node.value.value
+    return out
+
+
+#: memory spaces a BlockSpec can name that are not VMEM
+_NON_VMEM_SPACES = ("SMEM", "ANY", "HBM")
 
 
 def _index_map_streams(node: Optional[ast.AST]) -> bool:
@@ -185,7 +204,8 @@ class VmemBudgetRule(Rule):
         findings: List[Finding] = []
         mod = _module_key(ctx.path)
         mod_dims = MODULE_DIMS.get(mod, {})
-        sig_dims = _scrape_param_defaults(ctx.tree)
+        sig_dims = {**_scrape_param_defaults(ctx.tree),
+                    **_scrape_module_ints(ctx.tree)}
         expr_seqs = MODULE_EXPR_SEQS.get(mod, {})
 
         for fn in [n for n in ast.walk(ctx.tree)
@@ -336,6 +356,9 @@ class VmemBudgetRule(Rule):
         for k in expr.keywords:
             if k.arg == "index_map":
                 imap = k.value
+            if k.arg == "memory_space" and dotted_name(k.value).split(
+                    ".")[-1] in _NON_VMEM_SPACES:
+                return SpecInfo(kind, None, 0, False, True)
         streams = _index_map_streams(imap)
         if isinstance(shape_arg, (ast.Tuple, ast.List)):
             try:

@@ -136,9 +136,8 @@ def run_pipeline(world: SyntheticWorld, cfg: RankGraph2Config, *,
                      k_train=cfg.k_train, batch_format="dedup_ids")
     state, specs, optimizer = T.init_state(jax.random.key(seed), cfg,
                                            pool_size=pool_size)
-    step_fn = T.make_train_step(
-        cfg, optimizer,
-        features=T.make_feature_store(world.user_feat, world.item_feat))
+    step_fn = T.make_train_step(cfg, optimizer)
+    feats = T.make_feature_store(world.user_feat, world.item_feat)
 
     per_type = {et: batch_per_type for et in ("uu", "ui", "ii")
                 if et in edge_types or et == "ui"}
@@ -147,7 +146,7 @@ def run_pipeline(world: SyntheticWorld, cfg: RankGraph2Config, *,
         for t in range(steps):
             batch = jax.tree.map(jnp.asarray,
                                  ds.sample_batch(t, seed, per_type))
-            state, m = step_fn(state, batch, jax.random.key(1000 + t))
+            state, m = step_fn(state, batch, jax.random.key(1000 + t), feats)
             if log_every and t % log_every == 0:
                 print(f"  step {t}: total={float(m['total']):.3f} "
                       f"infonce_ui={float(m.get('infonce_ui', 0.0)):.3f}")

@@ -330,6 +330,7 @@ class ClusterQueueStore:
             )
         if device is not None:
             state = jax.device_put(state, device)
+        self.device = device
         self._state = state
         self._cursor_host = np.zeros(C, np.int64)
         self.d_count = 0               # filled delta slots (writer-only)
@@ -572,25 +573,28 @@ class ClusterQueueStore:
         return [int(i) for i in row if i >= 0]
 
     def _i2i_device(self, i2i: np.ndarray):
-        """Device copy of the I2I table, cached by identity (the table is
-        rebuilt only at embedding refresh, so one transfer per swap)."""
+        """Copy of the I2I table on this store's device, cached by
+        identity (the table is rebuilt only at embedding refresh, so one
+        transfer per swap)."""
         cached = self._i2i_cache
         if cached is not None and cached[0] == id(i2i):
             return cached[1]
-        dev = jnp.asarray(np.asarray(i2i, np.int32))
+        dev = jax.device_put(np.asarray(i2i, np.int32), self.device)
         self._i2i_cache = (id(i2i), dev)
         return dev
 
-    def _ring_view(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Consistent host view ``(items, times, cursor)`` of the ring
-        for the Pallas kernel path (delta mode folds first so the ring
-        is complete)."""
+    def _ring_state(self) -> Dict[str, jnp.ndarray]:
+        """One consistent snapshot of the device state whose ring holds
+        every event (delta mode folds first)."""
         if self.delta_cap:
             with self.write_lock:
                 self._fold()
-                st = self._state
-        else:
-            st = self._state
+                return self._state
+        return self._state
+
+    def _ring_view(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Consistent host view ``(items, times, cursor)`` of the ring."""
+        st = self._ring_state()
         return (np.asarray(st["items"]), np.asarray(st["times"]),
                 np.asarray(st["total"]).astype(np.int64))
 
@@ -614,14 +618,15 @@ class ClusterQueueStore:
         """Full serving pass: U2U2I seeds ``(B, n_recent)`` plus — when an
         ``i2i`` table is given — the U2I2I round-robin union ``(B, k)``.
         The default path fuses both stages into a single jitted dispatch;
-        ``use_kernel=True`` routes through the fused Pallas
-        ``queue_gather`` kernel on a host snapshot of the ring."""
+        ``use_kernel=True`` routes through the Pallas ``queue_gather``
+        kernels on the same device-resident ring snapshot."""
         if i2i is not None and use_kernel:
             from repro.kernels.queue_gather.ops import queue_gather
             user_ids = np.asarray(user_ids, np.int64).ravel()
             cl, known = self.clusters_of(user_ids)
-            items, times, cursor = self._ring_view()
-            s, u = queue_gather(items, times, cursor, cl, i2i,
+            st = self._ring_state()
+            s, u = queue_gather(st["items"], st["times"], st["total"], cl,
+                                self._i2i_device(i2i),
                                 cutoff=self.rel_cutoff(now),
                                 n_recent=n_recent, k=k)
             seeds = np.asarray(s, np.int64)

@@ -38,6 +38,7 @@ from repro.core import losses as L
 from repro.core import model as M
 from repro.core import negatives as N
 from repro.core import rq_index as RQ
+from repro.data.edge_dataset import pack_bucket
 from repro.distributed.sharding import ShardingCtx, NULL_CTX
 from repro.optim import optimizers as opt_lib
 
@@ -60,17 +61,29 @@ jax.tree_util.register_dataclass(
 class FeatureStore:
     """Device-resident raw feature tables for id-only batches.
 
-    Registered once and closed over by the jitted step: XLA keeps the
-    tables on device, so per-step host->device traffic is just the id /
-    mask integers of the batch."""
+    Passed to the jitted step as an argument (never donated): the tables
+    stay on device between steps, per-step host->device traffic is just
+    the id / mask integers of the batch, and the compiled program holds
+    no copy of the tables, whatever the corpus size."""
     user_feat: jnp.ndarray     # (n_users, d_user_feat) float32
     item_feat: jnp.ndarray     # (n_items, d_item_feat) float32
 
 
+jax.tree_util.register_dataclass(
+    FeatureStore, data_fields=["user_feat", "item_feat"], meta_fields=[])
+
+
 def make_feature_store(user_feat: np.ndarray, item_feat: np.ndarray
                        ) -> FeatureStore:
-    return FeatureStore(jnp.asarray(user_feat, jnp.float32),
-                        jnp.asarray(item_feat, jnp.float32))
+    """Upload the tables, zero-padding their rows to a ``pack_bucket``
+    size: ids never reach the pad, and an id space that grows by a few
+    percent keeps the table shapes — and so the compiled step."""
+    def put(x):
+        x = np.asarray(x, np.float32)
+        pad = pack_bucket(x.shape[0], 64) - x.shape[0]
+        return jnp.asarray(np.pad(x, ((0, pad), (0, 0))))
+
+    return FeatureStore(put(user_feat), put(item_feat))
 
 
 def init_state(key, cfg: RankGraph2Config, *, pool_size: int = 8192,
@@ -116,8 +129,8 @@ def _dedup_per_type(params, cfg: RankGraph2Config, batch,
         else:
             if features is None:
                 raise ValueError(
-                    "id-only batch but no FeatureStore; pass features= "
-                    "to make_train_step / make_eval_step")
+                    "id-only batch but no FeatureStore; pass features "
+                    "to the train step")
             table = (features.user_feat if ntype == M.USER
                      else features.item_feat)
             feat = jnp.take(table, side["ids"], axis=0)
@@ -256,19 +269,21 @@ def _forward_losses(params, cfg: RankGraph2Config, batch, pool, rq_state,
 def make_train_step(cfg: RankGraph2Config, optimizer: opt_lib.Optimizer,
                     ctx: ShardingCtx = NULL_CTX, *,
                     grad_clip: float = 1.0,
-                    features: Optional[FeatureStore] = None,
                     jit: bool = True, donate: bool = True):
-    """Builds train_step(state, batch, key) -> (state, metrics).
+    """Builds train_step(state, batch, key, features=None) ->
+    (state, metrics).
 
     By default the step comes back jitted with ``donate_argnums=0`` —
     the incoming ``TrainState`` buffers are reused for the outgoing
     state, halving peak state memory.  Callers that lower/compile the
     raw function themselves (dry-run, roofline) pass ``jit=False``.
-    ``features`` supplies the device-resident ``FeatureStore`` required
-    by id-only (``dedup_ids``) batches.
+    ``features`` is the ``FeatureStore`` required by id-only
+    (``dedup_ids``) batches; it is an argument, not a closure, so new
+    tables of the same shape reuse the compiled step.
     """
 
-    def train_step(state: TrainState, batch, key):
+    def train_step(state: TrainState, batch, key,
+                   features: Optional[FeatureStore] = None):
         def loss_fn(params):
             tasks, aux = _forward_losses(params, cfg, batch, state.pool,
                                          state.rq_state, key, ctx, True,
@@ -293,17 +308,6 @@ def make_train_step(cfg: RankGraph2Config, optimizer: opt_lib.Optimizer,
     if not jit:
         return train_step
     return jax.jit(train_step, donate_argnums=(0,) if donate else ())
-
-
-def make_eval_step(cfg: RankGraph2Config, ctx: ShardingCtx = NULL_CTX, *,
-                   features: Optional[FeatureStore] = None):
-    def eval_step(state: TrainState, batch, key):
-        tasks, _ = _forward_losses(state.params, cfg, batch, state.pool,
-                                   state.rq_state, key, ctx, False,
-                                   features)
-        return tasks
-
-    return eval_step
 
 
 # ---------------------------------------------------------------------------

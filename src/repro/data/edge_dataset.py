@@ -156,9 +156,17 @@ _ET_SIDES = {"uu": ("user", "user"), "ui": ("user", "item"),
 
 
 def _round_up(n: int, m: int) -> int:
-    """Bucket sizes to multiples of m (min m) so jit traces are reused
-    across batches instead of recompiling per unique-node count."""
+    """Round n up to a multiple of m (min m)."""
     return max(m, -(-n // m) * m)
+
+
+def pack_bucket(n: int, m: int) -> int:
+    """Bucket a pack size so jit traces are reused across batches instead
+    of recompiling per unique-node count: a multiple of m, coarsened to
+    about n/32 for large packs, so the few-per-mille batch-to-batch
+    jitter of a 30k-edge batch stays in one bucket (<= ~3% padding)."""
+    step = m * max(1, (1 << max(n.bit_length() - 5, 0)) // m)
+    return _round_up(n, step)
 
 
 @dataclasses.dataclass
@@ -276,7 +284,8 @@ class EdgeDataset:
 
         Pack layout per type: ``[endpoint uniques (E, sorted) | pad to
         E_pad | neighbor-only extras (sorted) | pad to U_pad]``; sizes
-        are bucketed to ``pad_multiple`` so jit traces are shared across
+        are bucketed to multiples of ``pad_multiple`` (coarser for large
+        packs, see ``pack_bucket``) so jit traces are shared across
         batches.  Endpoint rows [0, E) are the only ones aggregated;
         extras exist only to be feature-encoded and gathered as
         neighbors.
@@ -322,7 +331,7 @@ class EdgeDataset:
             allv = (np.unique(np.concatenate(valid)) if valid
                     else np.zeros(0, np.int64))
             extras[t] = np.setdiff1d(allv, uniq[t], assume_unique=True)
-            e_pad[t] = _round_up(len(uniq[t]), mult)
+            e_pad[t] = pack_bucket(len(uniq[t]), mult)
 
         def pack_index(t: str, gids: np.ndarray, mask: np.ndarray
                        ) -> np.ndarray:
@@ -338,7 +347,7 @@ class EdgeDataset:
 
         for t in ("user", "item"):
             E, Ep = len(uniq[t]), e_pad[t]
-            u_pad = _round_up(Ep + len(extras[t]), mult)
+            u_pad = pack_bucket(Ep + len(extras[t]), mult)
             local = np.zeros(u_pad, np.int64)
             off, hi = (0, nu - 1) if t == "user" else (nu, ni - 1)
             local[:E] = np.clip(uniq[t] - off, 0, hi)

@@ -24,6 +24,9 @@ import numpy as np
 from repro.core.graph_builder import EngagementLog
 
 
+_AFF_ROWS = 1 << 16     # events per affinity block in ``make_world``
+
+
 @dataclasses.dataclass
 class SyntheticWorld:
     user_latent: np.ndarray     # (n_users, T)
@@ -84,8 +87,13 @@ def make_world(n_users: int = 2000, n_items: int = 3000, *,
         # candidate subset (keeps this O(n_ev * C))
         C = min(256, n_items)
         cand = r.integers(0, n_items, (n_ev, C))
-        aff = np.einsum("et,ect->ec", zu[users],
-                        zi[cand]) / temp
+        # row blocks bound the (rows, C, T) gather of item latents
+        aff = np.empty((n_ev, C), np.float32)
+        for lo in range(0, n_ev, _AFF_ROWS):
+            hi = lo + _AFF_ROWS
+            aff[lo:hi] = np.einsum("et,ect->ec", zu[users[lo:hi]],
+                                   zi[cand[lo:hi]])
+        aff /= temp
         score = aff + pop_strength * np.log(pop[cand] + 1e-6) * 0.8
         g = r.gumbel(0, 1, score.shape)
         items = cand[np.arange(n_ev), np.argmax(score + g, axis=1)]

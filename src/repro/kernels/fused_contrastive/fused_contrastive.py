@@ -34,10 +34,7 @@ def _fwd_kernel(src_ref, dst_ref, neg_ref, marg_ref, info_ref, pos_ref,
     dst = dst_ref[...].astype(jnp.float32)          # (Bt, d)
     negs = neg_ref[...].astype(jnp.float32)         # (Bt, N, d)
     s_pos = jnp.sum(src * dst, axis=-1)             # (Bt,)
-    # batched (1, d) x (N, d)^T via dot_general with batch dims
-    s_neg = jax.lax.dot_general(
-        src, negs, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)          # (Bt, N)
+    s_neg = jnp.sum(src[:, None, :] * negs, axis=-1)  # (Bt, N)
     marg_ref[...] = jnp.sum(
         jnp.maximum(s_neg - s_pos[:, None] + margin, 0.0), axis=-1,
         keepdims=True)
@@ -66,18 +63,14 @@ def _bwd_kernel(src_ref, dst_ref, neg_ref, gm_ref, gi_ref, pos_ref, lse_ref,
     gi = gi_ref[...].astype(jnp.float32)            # (Bt, 1)
     s_pos = pos_ref[...].astype(jnp.float32)        # (Bt, 1)
     lse = lse_ref[...].astype(jnp.float32)          # (Bt, 1)
-    s_neg = jax.lax.dot_general(
-        src, negs, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)          # (Bt, N)
+    s_neg = jnp.sum(src[:, None, :] * negs, axis=-1)  # (Bt, N)
     active = (s_neg - s_pos + margin > 0.0).astype(jnp.float32)
     p_neg = jnp.exp(s_neg / tau - lse)
     a = gm * active + gi * (p_neg / tau)             # (Bt, N) dL/ds_neg
     p_pos = jnp.exp(s_pos / tau - lse)
     c = -gm * jnp.sum(active, axis=-1, keepdims=True) \
         + gi * (p_pos - 1.0) / tau                   # (Bt, 1) dL/ds_pos
-    dsrc_ref[...] = c * dst + jax.lax.dot_general(
-        a, negs, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)          # (Bt, d)
+    dsrc_ref[...] = c * dst + jnp.sum(a[:, :, None] * negs, axis=1)
     ddst_ref[...] = c * src
     dneg_ref[...] = a[:, :, None] * src[:, None, :]  # (Bt, N, d)
 

@@ -16,8 +16,9 @@ def queue_gather(items, times, cursor, clusters, i2i, *, cutoff: float,
     """Batched serving gather: U2U2I seeds + U2I2I round-robin union.
 
     items/times (C, Q) ring buffers, cursor (C,) total writes, clusters
-    (B,) per-request cluster ids, i2i (N, K) offline KNN table.  Returns
-    (seeds (B, n_recent), union (B, k)), both ``-1``-padded.
+    (B,) per-request cluster ids, i2i (N, K) offline KNN table — host or
+    device arrays (the kernel path reads device arrays in place).
+    Returns (seeds (B, n_recent), union (B, k)), both ``-1``-padded.
     """
     if use_kernel:
         return queue_gather_kernel(items, times, cursor, clusters, i2i,

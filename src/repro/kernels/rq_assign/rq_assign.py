@@ -25,8 +25,13 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, pad_to, should_interpret
+from repro.kernels.common import VMEM_LIMIT, cdiv, pad_to, should_interpret
+
+# full-f32 MXU passes: the one-hot gather must return codebook rows
+# exactly, and the distances must rank like the f32 reference
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _kernel(x_ref, *refs, n_layers: int, n_codes: Tuple[int, ...]):
@@ -41,7 +46,7 @@ def _kernel(x_ref, *refs, n_layers: int, n_codes: Tuple[int, ...]):
         C = code_refs[l][...].astype(jnp.float32)            # (n, d)
         # squared distances via MXU: ||r||^2 - 2 rC^T + ||C||^2
         cross = jax.lax.dot_general(
-            resid, C, (((1,), (1,)), ((), ())),
+            resid, C, (((1,), (1,)), ((), ())), precision=_EXACT,
             preferred_element_type=jnp.float32)              # (Bt, n)
         d2 = (jnp.sum(resid * resid, axis=1, keepdims=True)
               - 2.0 * cross + jnp.sum(C * C, axis=1)[None, :])
@@ -50,7 +55,7 @@ def _kernel(x_ref, *refs, n_layers: int, n_codes: Tuple[int, ...]):
                   jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
                   ).astype(jnp.float32)
         sel = jax.lax.dot_general(                            # (Bt, d) MXU
-            onehot, C, (((1,), (0,)), ((), ())),
+            onehot, C, (((1,), (0,)), ((), ())), precision=_EXACT,
             preferred_element_type=jnp.float32)
         resid = resid - sel
         recon = recon + sel
@@ -73,7 +78,9 @@ def _run(x, codebooks, *, block_b: int, interpret: bool):
                  pl.BlockSpec((block_b, d), lambda i: (i, 0)))
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shapes, interpret=interpret)(x, *codebooks)
+        out_shape=out_shapes, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT))(x, *codebooks)
 
 
 def rq_assign(x: jnp.ndarray, codebooks: Sequence[jnp.ndarray], *,

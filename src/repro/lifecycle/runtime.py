@@ -180,8 +180,8 @@ class LifecycleRuntime:
         self.item_feat = np.asarray(item_feat, np.float32)
         self.state, self.specs, self.optimizer = T.init_state(
             jax.random.key(seed), cfg)
-        self._step_fn = None         # built by _rebuild_dataset below
-        self._features_stale = True
+        self._step_fn = T.make_train_step(cfg, self.optimizer)
+        self._features = None        # placed by _rebuild_dataset below
         self.store = (SnapshotStore(snapshot_dir,
                                     keep=lcfg.snapshot_keep,
                                     faults=self.faults,
@@ -278,16 +278,12 @@ class LifecycleRuntime:
                                    k_train=self.cfg.k_train,
                                    batch_format="dedup_ids")
         # id-only batches gather features inside the jitted step from a
-        # device-resident store; the donated step only needs rebuilding
-        # when the feature tables themselves change (id-space growth or
-        # in-place edits) — graph/table refreshes alone keep the
-        # compiled step warm
-        if self._step_fn is None or self._features_stale:
-            self._step_fn = T.make_train_step(
-                self.cfg, self.optimizer,
-                features=T.make_feature_store(self.user_feat,
-                                              self.item_feat))
-            self._features_stale = False
+        # device-resident store passed as a step argument; it is only
+        # re-uploaded when the feature tables themselves change (id-space
+        # growth or in-place edits)
+        if self._features is None:
+            self._features = T.make_feature_store(self.user_feat,
+                                                  self.item_feat)
 
     def refresh(self, delta_log: EngagementLog, *,
                 user_feat: Optional[np.ndarray] = None,
@@ -305,10 +301,6 @@ class LifecycleRuntime:
             self.user_feat = np.asarray(user_feat, np.float32)
         if item_feat is not None:
             self.item_feat = np.asarray(item_feat, np.float32)
-        if user_feat is not None or item_feat is not None:
-            # explicit tables may be the same ndarray object mutated in
-            # place — always refresh the device-resident FeatureStore
-            self._features_stale = True
         # validate BEFORE mutating graph/tables: a failed refresh must
         # leave the runtime consistent (retrying after the error would
         # otherwise merge the same delta's aggregates twice)
@@ -316,6 +308,10 @@ class LifecycleRuntime:
             raise ValueError("user space grew without new user features")
         if self.item_feat.shape[0] < delta_log.n_items:
             raise ValueError("item space grew without new item features")
+        if user_feat is not None or item_feat is not None:
+            # explicit tables may be the same ndarray object mutated in
+            # place — always refresh the device-resident FeatureStore
+            self._features = None
         if prev_emb is not None and len(prev_emb) != (
                 delta_log.n_users + delta_log.n_items):
             prev_emb = None            # id space grew past the last embed
@@ -356,7 +352,8 @@ class LifecycleRuntime:
                     jnp.asarray, self.dataset.sample_batch(
                         base + t, self.seed, per_type))
                 self.state, m = self._step_fn(
-                    self.state, batch, jax.random.key(1000 + base + t))
+                    self.state, batch, jax.random.key(1000 + base + t),
+                    self._features)
                 if every > 0 and ((t + 1) % every == 0 or t + 1 == steps):
                     self.state, rep = T.reset_dead_codes(
                         self.state, self._probe_embeddings(base + t + 1),

@@ -225,13 +225,14 @@ class SwapServer:
 
     def __init__(self, snapshot: IndexSnapshot, *, queue_len: int = 256,
                  recency_s: float = 3600.0, ring_capacity: int = 1 << 16,
-                 n_shards: int = 1, delta_cap: int = 0,
+                 n_shards: int = 1, delta_cap: int = 0, mesh=None,
                  clock: Optional[Callable[[], float]] = None,
                  telemetry=None, faults=None):
         self.queue_len = int(queue_len)
         self.recency_s = float(recency_s)
         self.n_shards = max(int(n_shards), 1)
         self.delta_cap = int(delta_cap)
+        self.mesh = mesh                 # shard s lives on device s % |mesh|
         self.tel = telemetry if telemetry is not None else get_telemetry()
         self.faults = faults if faults is not None else get_faults()
         # injectable so swap-report timings are replayable in tests —
@@ -254,7 +255,7 @@ class SwapServer:
                                       recency_s=self.recency_s,
                                       n_clusters=snapshot.n_clusters,
                                       delta_cap=self.delta_cap,
-                                      telemetry=self.tel)
+                                      telemetry=self.tel, mesh=self.mesh)
         else:
             store = ClusterQueueStore(snapshot.user_clusters,
                                       queue_len=self.queue_len,

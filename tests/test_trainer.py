@@ -12,13 +12,15 @@ from repro.core import rq_index as RQ
 from repro.distributed.sharding import NULL_CTX
 
 
-def _step_n(state, step_fn, ds, per_type, seed, n, start=0, format=None):
+def _step_n(state, step_fn, ds, per_type, seed, n, start=0, format=None,
+            features=None):
     m = None
     for t in range(start, start + n):
         batch = jax.tree.map(jnp.asarray,
                              ds.sample_batch(t, seed, per_type,
                                              format=format))
-        state, m = step_fn(state, batch, jax.random.key(500 + t))
+        state, m = step_fn(state, batch, jax.random.key(500 + t),
+                           features)
     return state, m
 
 
@@ -148,9 +150,9 @@ def test_id_only_pipeline_trains_identically(tiny_cfg, tiny_dataset):
     def run(fmt, features=None):
         state, _, opt = T.init_state(jax.random.key(0), tiny_cfg,
                                      pool_size=128)
-        step = T.make_train_step(tiny_cfg, opt, features=features)
+        step = T.make_train_step(tiny_cfg, opt)
         return _step_n(state, step, tiny_dataset, per_type, 0, 4,
-                       format=fmt)[0]
+                       format=fmt, features=features)[0]
 
     feats = T.make_feature_store(tiny_dataset.user_feat,
                                  tiny_dataset.item_feat)
