@@ -177,16 +177,17 @@ def _direct_ingest_jit(st, w_cl, slot, w_item, raw_item, rel, t_cl,
     """Direct mode: tombstone prior ring occurrences of incoming items,
     then scatter the batch's surviving writes and advance cursors.  Pad
     rows carry cluster ``C`` and fall out via ``mode="drop"``."""
-    ring_rows = st["items"][jnp.clip(t_cl, 0, C - 1)]
-    m = ((ring_rows == raw_item[:, None])
-         & (raw_item >= 0)[:, None] & (t_cl < C)[:, None])
-    q_hit = jnp.argmax(m, axis=1).astype(jnp.int32)
-    has = m.any(axis=1)
-    items = st["items"].at[jnp.where(has, t_cl, C), q_hit].set(-1,
-                                                               mode="drop")
-    items = items.at[w_cl, slot].set(w_item, mode="drop")
-    times = st["times"].at[w_cl, slot].set(rel, mode="drop")
-    total = st["total"].at[ucl].add(cnt, mode="drop")
+    with jax.named_scope("ingest.scatter"):
+        ring_rows = st["items"][jnp.clip(t_cl, 0, C - 1)]
+        m = ((ring_rows == raw_item[:, None])
+             & (raw_item >= 0)[:, None] & (t_cl < C)[:, None])
+        q_hit = jnp.argmax(m, axis=1).astype(jnp.int32)
+        has = m.any(axis=1)
+        items = st["items"].at[jnp.where(has, t_cl, C), q_hit].set(
+            -1, mode="drop")
+        items = items.at[w_cl, slot].set(w_item, mode="drop")
+        times = st["times"].at[w_cl, slot].set(rel, mode="drop")
+        total = st["total"].at[ucl].add(cnt, mode="drop")
     return dict(items=items, times=times, total=total)
 
 
@@ -267,9 +268,12 @@ def _retrieve_jit(st, cl, cutoff, k, C, Q, Deff):
 @functools.partial(jax.jit,
                    static_argnames=("n_recent", "k", "C", "Q", "Deff"))
 def _serve_jit(st, cl, i2i, cutoff, n_recent, k, C, Q, Deff):
-    cand, valid = _candidate_window(st, cl, cutoff, C, Q, Deff)
-    seeds = _select_topk(cand, valid, n_recent)
-    return seeds, _union_topk(seeds, i2i, k)
+    # the scopes name the device operations in a profile (metadata only)
+    with jax.named_scope("serve.select"):
+        cand, valid = _candidate_window(st, cl, cutoff, C, Q, Deff)
+        seeds = _select_topk(cand, valid, n_recent)
+    with jax.named_scope("serve.union"):
+        return seeds, _union_topk(seeds, i2i, k)
 
 
 # ---------------------------------------------------------------------------
@@ -298,8 +302,8 @@ class ClusterQueueStore:
 
     def __init__(self, user_clusters: np.ndarray, *, queue_len: int = 256,
                  recency_s: float = 900.0, n_clusters: Optional[int] = None,
-                 telemetry=None, delta_cap: int = 0, shard_tag: str = "",
-                 device=None):
+                 telemetry=None, delta_cap: int = 0,
+                 shard: Optional[int] = None, device=None):
         self.tel = telemetry if telemetry is not None else get_telemetry()
         self.user_clusters = np.asarray(user_clusters, np.int64)
         self.queue_len = int(queue_len)
@@ -337,7 +341,11 @@ class ClusterQueueStore:
         self.epoch: Optional[float] = None
         self.write_lock = threading.RLock()
         self.ring_seen = 0     # EventRing watermark (maintained by swap)
-        self.shard_tag = shard_tag
+        # a shard's metrics carry a ``.shard{i}`` suffix, and its spans are
+        # children of its router's span
+        self.shard = shard
+        shard_tag = "" if shard is None else f".shard{shard}"
+        self._span_kw = {} if shard is None else {"shard": shard}
         self._m_ingest = "serving.ingest_events" + shard_tag
         self._m_requests = "serving.retrieve_requests" + shard_tag
         self._m_latency = "serving.retrieve_latency_s" + shard_tag
@@ -345,6 +353,9 @@ class ClusterQueueStore:
         self._m_depth_mean = "serving.queue_depth_mean" + shard_tag
         self._m_unknown_ev = "serving.unknown_user_events" + shard_tag
         self._m_unknown_rq = "serving.unknown_user_requests" + shard_tag
+        self._m_serve_calls = "serving.serve_calls" + shard_tag
+        self._m_serve_rows = "serving.serve_rows" + shard_tag
+        self._m_serve_padded = "serving.serve_rows_padded" + shard_tag
         self._i2i_cache: Optional[Tuple[int, jnp.ndarray]] = None
 
     # -- cluster assignment lookup ------------------------------------------
@@ -377,11 +388,30 @@ class ClusterQueueStore:
 
         The device scatter happens behind ``write_lock``; readers keep
         dispatching against the previous ``_state`` snapshot and observe
-        the batch atomically when the rebind lands."""
+        the batch atomically when the rebind lands.
+
+        Spans: ``serving.ingest`` (attrs ``events``, ``clusters``,
+        ``width``: padded event rows dispatched) over
+        ``serving.ingest.lookup`` (``clusters_of``),
+        ``serving.ingest.prep`` (time order, slot assignment, LWW,
+        padding) and ``serving.ingest.dispatch`` (the device copies and
+        the jitted write).  Under a router the router holds the parent
+        and the children carry ``shard``."""
         user_ids = np.asarray(user_ids, np.int64).ravel()
+        if self.shard is not None:
+            self._ingest(user_ids, item_ids, timestamps, _presorted, None)
+            return
+        with self.tel.hot_span("serving.ingest",
+                               events=int(user_ids.size)) as sp:
+            self._ingest(user_ids, item_ids, timestamps, _presorted, sp)
+
+    def _ingest(self, user_ids: np.ndarray, item_ids: np.ndarray,
+                timestamps: np.ndarray, presorted: bool, sp) -> None:
+        tel, kw = self.tel, self._span_kw
         item_ids = np.asarray(item_ids, np.int64).ravel()
         ts64 = np.asarray(timestamps, np.float64).ravel()
-        cl_all, known = self.clusters_of(user_ids)
+        with tel.hot_span("serving.ingest.lookup", **kw):
+            cl_all, known = self.clusters_of(user_ids)
         if not known.all():
             # graceful degradation: post-snapshot users are shed, not
             # errored — the drop is surfaced as a counter so staleness
@@ -394,124 +424,134 @@ class ClusterQueueStore:
         if cl_all.size == 0:
             return
         with self.write_lock:
-            if self.epoch is None:
-                self.epoch = float(ts64.min())
-            rel = (ts64 - self.epoch).astype(np.float32)
-            cl = cl_all.astype(np.int32)
-            it = item_ids.astype(np.int32)
-            if not _presorted:
-                order = np.argsort(rel, kind="stable")
-                cl, it, rel = cl[order], it[order], rel[order]
+            with tel.hot_span("serving.ingest.prep", **kw):
+                if self.epoch is None:
+                    self.epoch = float(ts64.min())
+                rel = (ts64 - self.epoch).astype(np.float32)
+                cl = cl_all.astype(np.int32)
+                it = item_ids.astype(np.int32)
+                if not presorted:
+                    order = np.argsort(rel, kind="stable")
+                    cl, it, rel = cl[order], it[order], rel[order]
             if self.delta_cap:
-                n, done = cl.size, 0
+                n, done, width, parts = cl.size, 0, 0, []
                 while done < n:
                     take = min(n - done, self.delta_cap - self.d_count)
                     if take == 0:
                         self._fold()
                         continue
-                    self._append(cl[done:done + take],
-                                 it[done:done + take],
-                                 rel[done:done + take])
+                    parts.append(self._append(cl[done:done + take],
+                                              it[done:done + take],
+                                              rel[done:done + take]))
+                    width += _bucket(take)
                     done += take
+                ucl = (parts[0] if len(parts) == 1
+                       else np.unique(np.concatenate(parts)))
             else:
-                self._direct_ingest(cl, it, rel)
-        tel = self.tel
+                ucl = self._direct_ingest(cl, it, rel)
+                width = _bucket(cl.size)
         if tel.enabled:
+            if sp is not None:
+                sp.set("clusters", int(ucl.size))
+                sp.set("width", width)
             tel.counter(self._m_ingest, float(cl.size))
-            fill = np.minimum(self._cursor_host[np.unique(cl)],
-                              self.queue_len)
+            fill = np.minimum(self._cursor_host[ucl], self.queue_len)
             tel.gauge(self._m_depth_max, float(fill.max()))
             tel.gauge(self._m_depth_mean, float(fill.mean()))
 
     def _direct_ingest(self, cl: np.ndarray, it: np.ndarray,
-                       rel: np.ndarray) -> None:
+                       rel: np.ndarray) -> np.ndarray:
         """Direct mode: host-side batch prep (slot assignment, in-batch
-        LWW) then one jitted scatter.  Reentrant under ``ingest``'s
-        lock."""
+        LWW) then one jitted scatter; returns the batch's unique
+        clusters.  Reentrant under ``ingest``'s lock."""
+        tel, kw = self.tel, self._span_kw
         with self.write_lock:
-            E = cl.size
-            C, Q = self.n_clusters, self.queue_len
-            # per-event sequence index within its cluster (vectorized):
-            # stable sort by cluster keeps time order inside each group
-            o = np.argsort(cl, kind="stable")
-            sc = cl[o]
-            start = np.zeros(E, np.int64)
-            if E > 1:
-                idx = np.arange(1, E)
-                start[1:] = np.where(sc[1:] == sc[:-1], 0, idx)
-                np.maximum.accumulate(start, out=start)
-            rank = np.arange(E) - start
-            seq = np.empty(E, np.int64)
-            seq[o] = self._cursor_host[sc] + rank
-            slot = (seq % Q).astype(np.int32)
-            # slot LWW (in-batch ring wrap): last event per (cl, slot)
-            skey = cl.astype(np.int64) * Q + slot
-            _, li = np.unique(skey[::-1], return_index=True)
-            keep = np.zeros(E, bool)
-            keep[E - 1 - li] = True
-            # in-batch item LWW: earlier duplicate of (cl, item) becomes
-            # a tombstone so the ring stays duplicate-free
-            ikey = cl.astype(np.int64) << 32 | it.astype(np.int64)
-            _, li2 = np.unique(ikey[::-1], return_index=True)
-            w_item = np.full(E, -1, np.int32)
-            last = E - 1 - li2
-            w_item[last] = it[last]
-            ucl, cnt = np.unique(cl, return_counts=True)
-            pad = _bucket(E) - E
-            Cp = _bucket(ucl.size)
-            self._state = _direct_ingest_jit(
-                self._state,
-                jnp.asarray(np.pad(np.where(keep, cl, C), (0, pad),
-                                   constant_values=C).astype(np.int32)),
-                jnp.asarray(np.pad(slot, (0, pad))),
-                jnp.asarray(np.pad(w_item, (0, pad), constant_values=-1)),
-                jnp.asarray(np.pad(it, (0, pad), constant_values=-1)),
-                jnp.asarray(np.pad(rel, (0, pad),
-                                   constant_values=-np.inf)),
-                jnp.asarray(np.pad(cl, (0, pad), constant_values=C)),
-                jnp.asarray(np.pad(ucl, (0, Cp - ucl.size),
-                                   constant_values=C).astype(np.int32)),
-                jnp.asarray(np.pad(cnt, (0, Cp - ucl.size)
-                                   ).astype(np.int32)),
-                C, Q)
+            with tel.hot_span("serving.ingest.prep", **kw):
+                E = cl.size
+                C, Q = self.n_clusters, self.queue_len
+                # per-event sequence index within its cluster (vectorized):
+                # stable sort by cluster keeps time order inside each group
+                o = np.argsort(cl, kind="stable")
+                sc = cl[o]
+                start = np.zeros(E, np.int64)
+                if E > 1:
+                    idx = np.arange(1, E)
+                    start[1:] = np.where(sc[1:] == sc[:-1], 0, idx)
+                    np.maximum.accumulate(start, out=start)
+                rank = np.arange(E) - start
+                seq = np.empty(E, np.int64)
+                seq[o] = self._cursor_host[sc] + rank
+                slot = (seq % Q).astype(np.int32)
+                # slot LWW (in-batch ring wrap): last event per (cl, slot)
+                skey = cl.astype(np.int64) * Q + slot
+                _, li = np.unique(skey[::-1], return_index=True)
+                keep = np.zeros(E, bool)
+                keep[E - 1 - li] = True
+                # in-batch item LWW: earlier duplicate of (cl, item) becomes
+                # a tombstone so the ring stays duplicate-free
+                ikey = cl.astype(np.int64) << 32 | it.astype(np.int64)
+                _, li2 = np.unique(ikey[::-1], return_index=True)
+                w_item = np.full(E, -1, np.int32)
+                last = E - 1 - li2
+                w_item[last] = it[last]
+                ucl, cnt = np.unique(cl, return_counts=True)
+                pad = _bucket(E) - E
+                Cp = _bucket(ucl.size)
+                args = (np.pad(np.where(keep, cl, C), (0, pad),
+                               constant_values=C).astype(np.int32),
+                        np.pad(slot, (0, pad)),
+                        np.pad(w_item, (0, pad), constant_values=-1),
+                        np.pad(it, (0, pad), constant_values=-1),
+                        np.pad(rel, (0, pad), constant_values=-np.inf),
+                        np.pad(cl, (0, pad), constant_values=C),
+                        np.pad(ucl, (0, Cp - ucl.size),
+                               constant_values=C).astype(np.int32),
+                        np.pad(cnt, (0, Cp - ucl.size)).astype(np.int32))
+            with tel.hot_span("serving.ingest.dispatch", **kw):
+                self._state = _direct_ingest_jit(
+                    self._state, *map(jnp.asarray, args), C, Q)
             self._cursor_host[ucl] += cnt
+        return ucl
 
     def _append(self, cl: np.ndarray, it: np.ndarray,
-                rel: np.ndarray) -> None:
+                rel: np.ndarray) -> np.ndarray:
         """Delta mode: append ``E <= delta_cap - d_count`` events to the
-        delta run.  Reentrant under ``ingest``'s lock."""
+        delta run; returns their unique clusters.  Reentrant under
+        ``ingest``'s lock."""
+        tel, kw = self.tel, self._span_kw
         with self.write_lock:
-            E = cl.size
-            C, Q, D = self.n_clusters, self.queue_len, self.delta_cap
-            o = np.argsort(cl, kind="stable")
-            sc = cl[o]
-            start = np.zeros(E, np.int64)
-            if E > 1:
-                idx = np.arange(1, E)
-                start[1:] = np.where(sc[1:] == sc[:-1], 0, idx)
-                np.maximum.accumulate(start, out=start)
-            rank = np.arange(E) - start
-            d_idx = np.empty(E, np.int64)
-            d_idx[o] = self._cursor_host[sc] + rank
-            key = cl.astype(np.int64) << 32 | it.astype(np.int64)
-            _, li = np.unique(key[::-1], return_index=True)
-            last = E - 1 - li
-            w_item = np.full(E, -1, np.int32)
-            w_item[last] = it[last]
-            ucl, cnt = np.unique(cl, return_counts=True)
-            pad = _bucket(E) - E
-            self._state = {**self._state, **_append_jit(
-                self._state,
-                jnp.asarray(np.pad(cl, (0, pad), constant_values=C)),
-                jnp.asarray(np.pad(w_item, (0, pad), constant_values=-1)),
-                jnp.asarray(np.pad(it, (0, pad), constant_values=-1)),
-                jnp.asarray(np.pad(rel, (0, pad),
-                                   constant_values=-np.inf)),
-                jnp.asarray(np.pad(d_idx, (0, pad)).astype(np.int32)),
-                jnp.int32(self.d_count), jnp.int32(E),
-                C, Q, D, D)}
+            with tel.hot_span("serving.ingest.prep", **kw):
+                E = cl.size
+                C, Q, D = self.n_clusters, self.queue_len, self.delta_cap
+                o = np.argsort(cl, kind="stable")
+                sc = cl[o]
+                start = np.zeros(E, np.int64)
+                if E > 1:
+                    idx = np.arange(1, E)
+                    start[1:] = np.where(sc[1:] == sc[:-1], 0, idx)
+                    np.maximum.accumulate(start, out=start)
+                rank = np.arange(E) - start
+                d_idx = np.empty(E, np.int64)
+                d_idx[o] = self._cursor_host[sc] + rank
+                key = cl.astype(np.int64) << 32 | it.astype(np.int64)
+                _, li = np.unique(key[::-1], return_index=True)
+                last = E - 1 - li
+                w_item = np.full(E, -1, np.int32)
+                w_item[last] = it[last]
+                ucl, cnt = np.unique(cl, return_counts=True)
+                pad = _bucket(E) - E
+                args = (np.pad(cl, (0, pad), constant_values=C),
+                        np.pad(w_item, (0, pad), constant_values=-1),
+                        np.pad(it, (0, pad), constant_values=-1),
+                        np.pad(rel, (0, pad), constant_values=-np.inf),
+                        np.pad(d_idx, (0, pad)).astype(np.int32))
+            with tel.hot_span("serving.ingest.dispatch", **kw):
+                self._state = {**self._state, **_append_jit(
+                    self._state, *map(jnp.asarray, args),
+                    jnp.int32(self.d_count), jnp.int32(E), C, Q, D, D)}
             self.d_count += E
             self._cursor_host[ucl] += cnt
+        return ucl
 
     def _fold(self) -> None:
         """Fold the pending delta run into the ring (no-op when empty).
@@ -519,9 +559,11 @@ class ClusterQueueStore:
         with self.write_lock:
             if self.d_count == 0:
                 return
-            self._state = {**self._state,
-                           **_fold_jit(self._state, self.n_clusters,
-                                       self.queue_len, self.delta_cap)}
+            with self.tel.hot_span("serving.ingest.dispatch",
+                                   **self._span_kw):
+                self._state = {**self._state,
+                               **_fold_jit(self._state, self.n_clusters,
+                                           self.queue_len, self.delta_cap)}
             self.d_count = 0
 
     # -- retrieval ----------------------------------------------------------
@@ -619,7 +661,16 @@ class ClusterQueueStore:
         ``i2i`` table is given — the U2I2I round-robin union ``(B, k)``.
         The default path fuses both stages into a single jitted dispatch;
         ``use_kernel=True`` routes through the Pallas ``queue_gather``
-        kernels on the same device-resident ring snapshot."""
+        kernels on the same device-resident ring snapshot.
+
+        The fused path's span ``serving.serve_batch`` (attrs
+        ``requests``, ``unique``, ``width``) has four children:
+        ``serving.serve.prep`` (cluster lookup, dedup, padding, the copy
+        of the cluster ids to the device), ``.dispatch`` (the enqueue of
+        ``_serve_jit``), ``.fetch`` (the wait for the device and the copy
+        back) and ``.expand`` (rows back in request order).  Under a
+        router the router holds the parent and the children carry
+        ``shard``."""
         if i2i is not None and use_kernel:
             from repro.kernels.queue_gather.ops import queue_gather
             user_ids = np.asarray(user_ids, np.int64).ravel()
@@ -642,22 +693,57 @@ class ClusterQueueStore:
             seeds = self.retrieve_batch(user_ids, now, n_recent)
             return seeds, np.full((seeds.shape[0], k), -1, np.int64)
         tel = self.tel
-        t0 = tel.clock.perf() if tel.enabled else 0.0
         user_ids = np.asarray(user_ids, np.int64).ravel()
-        cl_p, inv, Bu, _, known = self._padded_clusters(user_ids)
-        st = self._state
-        s, u = _serve_jit(st, jnp.asarray(cl_p), self._i2i_device(i2i),
-                          jnp.float32(self.rel_cutoff(now)),
-                          int(n_recent), int(k),
-                          self.n_clusters, self.queue_len, self.delta_cap)
-        seeds = np.asarray(s)[:Bu][inv].astype(np.int64)
-        union = np.asarray(u)[:Bu][inv].astype(np.int64)
+        if self.shard is not None:
+            seeds, union, known, busy = self._serve_fused(
+                user_ids, now, n_recent, k, i2i, None)
+        else:
+            with tel.hot_span("serving.serve_batch",
+                              requests=int(user_ids.size)) as sp:
+                seeds, union, known, _ = self._serve_fused(
+                    user_ids, now, n_recent, k, i2i, sp)
+            busy = sp.duration_s
         if tel.enabled:
-            tel.observe(self._m_latency, tel.clock.perf() - t0)
+            tel.observe(self._m_latency, busy)
             tel.counter(self._m_requests)
             if not known.all():
                 tel.counter(self._m_unknown_rq, float((~known).sum()))
         return seeds, union
+
+    def _serve_fused(self, user_ids: np.ndarray, now: float,
+                     n_recent: int, k: int, i2i: np.ndarray, sp
+                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, float]:
+        """The fused serve pass under its four spans; returns seeds,
+        union, the known-user mask and the seconds of the four spans.
+        ``sp`` is the parent span (``None`` under a router)."""
+        tel, kw = self.tel, self._span_kw
+        with tel.hot_span("serving.serve.prep", **kw) as prep:
+            cl_p, inv, Bu, _, known = self._padded_clusters(user_ids)
+            cl_dev = jnp.asarray(cl_p)
+        with tel.hot_span("serving.serve.dispatch", **kw) as dispatch:
+            s, u = _serve_jit(self._state, cl_dev, self._i2i_device(i2i),
+                              jnp.float32(self.rel_cutoff(now)),
+                              int(n_recent), int(k), self.n_clusters,
+                              self.queue_len, self.delta_cap)
+        with tel.hot_span("serving.serve.fetch", **kw) as fetch:
+            s, u = np.asarray(s), np.asarray(u)
+        with tel.hot_span("serving.serve.expand", **kw) as expand:
+            seeds = s[:Bu][inv].astype(np.int64)
+            union = u[:Bu][inv].astype(np.int64)
+        if tel.enabled:
+            width = int(cl_p.size)
+            if sp is not None:
+                sp.set("unique", Bu)
+                sp.set("width", width)
+            tel.counter(self._m_serve_calls)
+            tel.counter(self._m_serve_rows, float(Bu))
+            tel.counter(self._m_serve_padded, float(width))
+            if self.shard is not None:   # the router's aggregate
+                tel.counter("serving.serve_rows", float(Bu))
+                tel.counter("serving.serve_rows_padded", float(width))
+        busy = (prep.duration_s + dispatch.duration_s + fetch.duration_s
+                + expand.duration_s)
+        return seeds, union, known, busy
 
     # -- introspection ------------------------------------------------------
 
@@ -731,7 +817,7 @@ class ShardedQueueStore:
             shards.append(ClusterQueueStore(
                 sub, queue_len=self.queue_len, recency_s=self.recency_s,
                 n_clusters=max(hi - lo, 1), telemetry=self.tel,
-                delta_cap=self.delta_cap, shard_tag=f".shard{s}",
+                delta_cap=self.delta_cap, shard=s,
                 device=devices[s % len(devices)] if devices else None))
             spans.append((lo, hi))
         self.shards: Tuple[ClusterQueueStore, ...] = tuple(shards)
@@ -761,11 +847,21 @@ class ShardedQueueStore:
     def ingest(self, user_ids: np.ndarray, item_ids: np.ndarray,
                timestamps: np.ndarray) -> None:
         """Sort the batch by time once, split by owning shard, scatter.
-        Per-shard ingests skip their own sort (``_presorted``)."""
+        Per-shard ingests skip their own sort (``_presorted``).  The
+        ``serving.ingest`` span is held here; each shard's children
+        carry its ``shard``."""
         user_ids = np.asarray(user_ids, np.int64).ravel()
+        with self.tel.hot_span("serving.ingest",
+                               events=int(user_ids.size)) as sp:
+            self._ingest(user_ids, item_ids, timestamps, sp)
+
+    def _ingest(self, user_ids: np.ndarray, item_ids: np.ndarray,
+                timestamps: np.ndarray, sp) -> None:
+        tel = self.tel
         item_ids = np.asarray(item_ids, np.int64).ravel()
         ts64 = np.asarray(timestamps, np.float64).ravel()
-        cl, known = self.clusters_of(user_ids)
+        with tel.hot_span("serving.ingest.lookup"):
+            cl, known = self.clusters_of(user_ids)
         if not known.all():
             if self.tel.enabled:
                 self.tel.counter("serving.unknown_user_events",
@@ -786,20 +882,22 @@ class ShardedQueueStore:
                         sh.epoch = self.epoch
             # sort by the same f32 relative key the unsharded store uses
             # (stable), so per-shard ring order is bitwise-identical
-            rel = (ts64 - self.epoch).astype(np.float32)
-            order = np.argsort(rel, kind="stable")
-            user_ids, item_ids = user_ids[order], item_ids[order]
-            ts64, cl = ts64[order], cl[order]
-            sid = np.searchsorted(self.bounds, cl, side="right") - 1
+            with tel.hot_span("serving.ingest.prep"):
+                rel = (ts64 - self.epoch).astype(np.float32)
+                order = np.argsort(rel, kind="stable")
+                user_ids, item_ids = user_ids[order], item_ids[order]
+                ts64, cl = ts64[order], cl[order]
+                sid = np.searchsorted(self.bounds, cl, side="right") - 1
             for s, sh in enumerate(self.shards):
                 m = sid == s
                 if m.any():
                     sh.ingest(user_ids[m], item_ids[m], ts64[m],
                               _presorted=True)
-        tel = self.tel
         if tel.enabled:
+            ucl = np.unique(cl)
+            sp.set("clusters", int(ucl.size))
             tel.counter("serving.ingest_events", float(cl.size))
-            fill = np.minimum(self.cursor[np.unique(cl)], self.queue_len)
+            fill = np.minimum(self.cursor[ucl], self.queue_len)
             tel.gauge("serving.queue_depth_max", float(fill.max()))
             tel.gauge("serving.queue_depth_mean", float(fill.mean()))
 
@@ -840,21 +938,34 @@ class ShardedQueueStore:
                     i2i: Optional[np.ndarray] = None,
                     use_kernel: bool = False
                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Scatter the serve pass across shards and merge both outputs."""
+        """Scatter the serve pass across shards and merge both outputs,
+        under the ``serving.serve_batch`` span (the shards' spans are its
+        children)."""
+        tel = self.tel
         user_ids = np.asarray(user_ids, np.int64).ravel()
-        cl, known = self.clusters_of(user_ids)
-        sid = self._shard_of(cl, known)
-        seeds = np.full((user_ids.size, int(n_recent)), -1, np.int64)
-        union = np.full((user_ids.size, int(k)), -1, np.int64)
-        for s, sh in enumerate(self.shards):
-            m = sid == s
-            if m.any():
-                s_out, u_out = sh.serve_batch(user_ids[m], now,
-                                              n_recent=n_recent, k=k,
-                                              i2i=i2i,
-                                              use_kernel=use_kernel)
-                seeds[m] = s_out
-                union[m] = u_out
+        with tel.hot_span("serving.serve_batch",
+                          requests=int(user_ids.size)) as sp:
+            cl, known = self.clusters_of(user_ids)
+            sid = self._shard_of(cl, known)
+            seeds = np.full((user_ids.size, int(n_recent)), -1, np.int64)
+            union = np.full((user_ids.size, int(k)), -1, np.int64)
+            for s, sh in enumerate(self.shards):
+                m = sid == s
+                if m.any():
+                    s_out, u_out = sh.serve_batch(user_ids[m], now,
+                                                  n_recent=n_recent, k=k,
+                                                  i2i=i2i,
+                                                  use_kernel=use_kernel)
+                    seeds[m] = s_out
+                    union[m] = u_out
+        if tel.enabled:
+            tel.observe("serving.retrieve_latency_s", sp.duration_s)
+            tel.counter("serving.retrieve_requests")
+            if i2i is not None and not use_kernel:
+                tel.counter("serving.serve_calls")
+            if not known.all():
+                tel.counter("serving.unknown_user_requests",
+                            float((~known).sum()))
         return seeds, union
 
     # -- introspection ------------------------------------------------------

@@ -278,9 +278,10 @@ class SwapServer:
         store and advance the bundle's watermark.  Safe under writer
         races: the (read watermark -> ingest -> advance) section runs
         under the store's write lock, so each ring position is applied
-        to this store exactly once.  Returns ``(applied, stale)``."""
+        to this store exactly once.  Returns ``(applied, stale)``.
+        Traced as the ``swap.drain`` span."""
         store = bundle.store
-        with store.write_lock:
+        with store.write_lock, self.tel.hot_span("swap.drain"):
             u, i, t, end = self.ring.window_since(store.ring_seen, -np.inf)
             stale = 0
             if min_ts > -np.inf and len(t):
@@ -306,25 +307,32 @@ class SwapServer:
         instead of erroring the caller — serving stays up, the loss is
         surfaced through the ring-drop counters (``swap.ring_dropped``
         plus ``swap.ingest_shed_batches``), and the already-committed
-        ring prefix stays intact for exactly-once replay."""
+        ring prefix stays intact for exactly-once replay.
+
+        Traced as ``swap.ingest`` over ``swap.ring_push`` (the ring
+        write) and ``swap.drain`` (into the live store)."""
         n = np.asarray(user_ids).size
-        try:
-            self.faults.fire("ring.push", n=n)
-            dropped = self.ring.push(user_ids, item_ids, timestamps)
-        except InjectedCrash:
-            raise                       # simulated process death
-        except Exception:
-            # overload shed: count the whole batch as dropped, keep serving
-            with self._stats_lock:
-                self.ring_dropped += n
-            self.tel.counter("swap.ring_dropped", float(n))
-            self.tel.counter("swap.ingest_shed_batches")
-            return
-        if dropped:
-            with self._stats_lock:
-                self.ring_dropped += dropped
-            self.tel.counter("swap.ring_dropped", float(dropped))
-        self._drain_into(self.handle.acquire())
+        with self.tel.hot_span("swap.ingest", events=int(n)):
+            try:
+                self.faults.fire("ring.push", n=n)
+                with self.tel.hot_span("swap.ring_push"):
+                    dropped = self.ring.push(user_ids, item_ids,
+                                             timestamps)
+            except InjectedCrash:
+                raise                   # simulated process death
+            except Exception:
+                # overload shed: count the whole batch as dropped, keep
+                # serving
+                with self._stats_lock:
+                    self.ring_dropped += n
+                self.tel.counter("swap.ring_dropped", float(n))
+                self.tel.counter("swap.ingest_shed_batches")
+                return
+            if dropped:
+                with self._stats_lock:
+                    self.ring_dropped += dropped
+                self.tel.counter("swap.ring_dropped", float(dropped))
+            self._drain_into(self.handle.acquire())
 
     def retrieve_batch(self, user_ids, now: float, k: int
                        ) -> Tuple[np.ndarray, int]:

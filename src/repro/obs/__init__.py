@@ -2,8 +2,10 @@
 
 Three primitives behind one facade:
 
-* **spans** — context-manager timers emitting JSONL trace events with
-  name, parent, wall time, duration, and free-form attrs;
+* **spans** — context-manager timers with name, parent, duration and
+  free-form attrs, buffered per thread and written as JSONL trace
+  events at ``flush`` (optionally also entered as a profiler annotation
+  through ``configure(annotate=...)``);
 * **counters / gauges** — thread-safe registry with per-thread shards
   merged on read, so the serving hot path never takes a contended lock;
 * **histograms** — fixed log-spaced buckets (1 µs base, √2 growth) with

@@ -1,9 +1,9 @@
 """Telemetry sinks — where serialized JSONL records go.
 
-Sinks receive *pre-serialized* lines (no trailing newline) so the hot
-path pays the ``json.dumps`` cost exactly once and a sink never has to
-understand record schemas. :class:`JsonlSink` is bounded: when the
-active file would exceed ``max_bytes`` it shift-rotates
+Sinks receive *pre-serialized* lines (no trailing newline), written at
+``Telemetry.flush`` rather than on the instrumented thread, so a sink
+never has to understand record schemas. :class:`JsonlSink` is bounded:
+when the active file would exceed ``max_bytes`` it shift-rotates
 (``f.jsonl.1`` → ``f.jsonl.2`` …, oldest dropped past ``max_files``),
 so a long-running process can emit forever without unbounded disk use.
 """
@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import List
+from typing import Callable, List, Optional
 
 
 class Sink:
@@ -19,6 +19,10 @@ class Sink:
 
     def write_line(self, line: str) -> None:
         raise NotImplementedError
+
+    def bind(self, drain: Optional[Callable[[], None]]) -> None:
+        """Called by the owning telemetry with the function that writes
+        its buffered spans into this sink (``None`` on release)."""
 
     def flush(self) -> None:  # pragma: no cover - trivial default
         pass
@@ -35,19 +39,34 @@ class NullSink(Sink):
 
 
 class MemorySink(Sink):
-    """Accumulates lines in memory — the workhorse for tests."""
+    """Accumulates lines in memory — the workhorse for tests.
+
+    Read-through: reading :attr:`lines` or :meth:`text` first writes the
+    spans its telemetry still buffers, so a reader sees every closed
+    span without a ``flush()`` (metrics still need one)."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self.lines: List[str] = []
+        self._lines: List[str] = []
+        self._drain: Optional[Callable[[], None]] = None
+
+    def bind(self, drain: Optional[Callable[[], None]]) -> None:
+        self._drain = drain
+
+    @property
+    def lines(self) -> List[str]:
+        if self._drain is not None:
+            self._drain()
+        return self._lines
 
     def write_line(self, line: str) -> None:
         with self._lock:
-            self.lines.append(line)
+            self._lines.append(line)
 
     def text(self) -> str:
+        lines = self.lines
         with self._lock:
-            return "".join(ln + "\n" for ln in self.lines)
+            return "".join(ln + "\n" for ln in lines)
 
 
 class JsonlSink(Sink):

@@ -445,3 +445,166 @@ class TestReport:
         counters, _, _ = report_mod.metric_summary(
             report_mod.load_records([p]))
         assert counters["x"] == 1
+
+
+# ---------------------------------------------------------------------------
+# buffered spans: per-thread buffers, one clock offset, the annotate hook
+# ---------------------------------------------------------------------------
+
+class _CountingClock(FixedClock):
+    def __init__(self):
+        super().__init__(wall=5000.0)
+        self.wall_reads = 0
+
+    def wall(self):
+        self.wall_reads += 1
+        return super().wall()
+
+
+class _Hook:
+    """An annotate factory that records every name, attr and exit."""
+
+    def __init__(self):
+        self.calls = []
+
+    def __call__(self, name, **attrs):
+        hook = self
+
+        class _Ann:
+            def __init__(self):
+                self.rec = {"name": name, "attrs": dict(attrs),
+                            "exited": False}
+                hook.calls.append(self.rec)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                self.rec["exited"] = True
+
+            def set_metadata(self, **kw):
+                self.rec["attrs"].update(kw)
+
+        return _Ann()
+
+
+class TestBufferedSpans:
+    def test_four_threads_reach_flush_with_the_schema(self, tmp_path):
+        p = str(tmp_path / "s.jsonl")
+        tel = Telemetry(sink=JsonlSink(p), clock=FixedClock())
+        barrier = threading.Barrier(4)
+
+        def work(tid):
+            barrier.wait(5)
+            for i in range(50):
+                with tel.span("outer", t=tid):
+                    with tel.span("inner", i=i):
+                        pass
+
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not os.path.exists(p)       # nothing written before flush
+        assert len(tel.spans()) == 400
+        tel.flush()
+        recs = [json.loads(ln) for ln in open(p)]
+        spans = [r for r in recs if r["type"] == "span"]
+        assert len(spans) == 400
+        for r in spans:
+            assert set(r) == {"type", "name", "span_id", "parent_id",
+                              "thread", "t_wall", "dur_s", "attrs"}
+        outer = {r["span_id"]: r for r in spans if r["name"] == "outer"}
+        for r in spans:
+            if r["name"] == "inner":
+                parent = outer[r["parent_id"]]
+                assert parent["thread"] == r["thread"]
+        assert len({r["thread"] for r in spans}) == 4
+        assert tel.spans() == []           # flush drained the buffers
+
+    def test_t_wall_comes_from_one_offset(self):
+        clock = _CountingClock()
+        sink = MemorySink()
+        tel = Telemetry(sink=sink, clock=clock)
+        reads = clock.wall_reads
+        for i in range(5):
+            with tel.span("s", i=i):
+                pass
+        assert clock.wall_reads == reads   # spans never read the wall
+        buffered = tel.spans()
+        recs = records(sink)
+        offsets = {round(r["t_wall"] - b["start"], 9)
+                   for r, b in zip(recs, buffered)}
+        assert len(offsets) == 1
+        assert [r["dur_s"] for r in recs] == [b["dur_s"] for b in buffered]
+        assert [r["attrs"]["i"] for r in recs] == list(range(5))
+
+    def test_annotate_hook_sees_names_and_attrs(self):
+        hook = _Hook()
+        tel = Telemetry(sink=MemorySink(), clock=FixedClock(),
+                        annotate=hook)
+        with tel.span("outer", requests=3) as sp:
+            with tel.span("inner"):
+                pass
+            sp.set("width", 8)
+        assert [c["name"] for c in hook.calls] == ["outer", "inner"]
+        assert hook.calls[0]["attrs"] == {"requests": 3, "width": 8}
+        assert hook.calls[1]["attrs"] == {}
+        assert all(c["exited"] for c in hook.calls)
+        tel.reconfigure(enabled=True)      # leaving annotate out keeps it
+        with tel.span("again"):
+            pass
+        assert hook.calls[-1]["name"] == "again"
+        tel.reconfigure(annotate=None)
+        with tel.span("no_hook"):
+            pass
+        assert hook.calls[-1]["name"] == "again"
+
+    def test_disabled_buffers_nothing_and_never_calls_the_hook(self):
+        hook = _Hook()
+        tel = Telemetry(sink=MemorySink(), clock=FixedClock(),
+                        enabled=False, annotate=hook)
+        with tel.span("s", a=1) as sp:
+            sp.set("b", 2)
+        assert tel.spans() == []
+        assert hook.calls == []
+        assert sp.duration_s > 0           # still measures
+
+    def test_hot_span_costs_nothing_while_disabled(self):
+        class Counting(FixedClock):
+            reads = 0
+
+            def perf(self):
+                Counting.reads += 1
+                return super().perf()
+
+        hook = _Hook()
+        tel = Telemetry(sink=MemorySink(), clock=Counting(), enabled=False,
+                        annotate=hook)
+        before = Counting.reads
+        with tel.hot_span("a", k=1) as a:
+            with tel.hot_span("b") as b:
+                b.set("x", 2)
+        assert a is b                      # one shared no-op
+        assert Counting.reads == before and hook.calls == []
+        assert tel.spans() == []
+        tel.reconfigure(enabled=True)
+        with tel.hot_span("a", k=1) as a:
+            with tel.hot_span("b") as b:
+                b.set("x", 2)
+        recs = {s["name"]: s for s in tel.spans()}
+        assert recs["b"]["parent_id"] == recs["a"]["span_id"]
+        assert recs["a"]["attrs"] == {"k": 1} and recs["b"]["dur_s"] > 0
+        assert [c["name"] for c in hook.calls] == ["a", "b"]
+
+    def test_reset_spans_drops_the_buffer(self):
+        tel, sink = make_tel()
+        with tel.span("old"):
+            pass
+        tel.reset_spans()
+        with tel.span("new"):
+            pass
+        assert [s["name"] for s in tel.spans()] == ["new"]
+        assert [r["name"] for r in records(sink)] == ["new"]

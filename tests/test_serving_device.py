@@ -245,6 +245,129 @@ def test_sharded_telemetry_tagged_counters_and_gauges():
     assert snap["hists"]["serving.retrieve_latency_s"].get("n", 0) >= 1
 
 
+# ---------------------------------------------------------------------------
+# spans and counters inside serve and ingest
+# ---------------------------------------------------------------------------
+
+SERVE_CHILDREN = ["serving.serve.prep", "serving.serve.dispatch",
+                  "serving.serve.fetch", "serving.serve.expand"]
+
+
+def _children(spans, parent):
+    return [s for s in spans if s["parent_id"] == parent["span_id"]]
+
+
+def test_serve_batch_spans_and_counters_agree_with_padding():
+    rng = np.random.default_rng(21)
+    flat = _clusters(rng)
+    tel = Telemetry()
+    dev = ClusterQueueStore(flat, queue_len=8, recency_s=1e9,
+                            telemetry=tel)
+    _ingest_both((dev,), rng)
+    i2i = rng.integers(0, N_ITEMS, (N_ITEMS, 3)).astype(np.int64)
+    tel.reset_spans()
+    tel.reset_metrics()
+    batches = [_PROBES, np.arange(N_USERS), np.array([3])]
+    for users in batches:
+        dev.serve_batch(users, 100.0, n_recent=4, k=8, i2i=i2i)
+    spans = tel.spans()
+    parents = [s for s in spans if s["name"] == "serving.serve_batch"]
+    assert len(parents) == len(batches)
+    rows = padded = 0
+    for users, parent in zip(batches, parents):
+        assert parent["parent_id"] is None
+        kids = _children(spans, parent)
+        assert [k["name"] for k in kids] == SERVE_CHILDREN
+        assert sum(k["dur_s"] for k in kids) <= parent["dur_s"]
+        cl_p, _, Bu, _, _ = dev._padded_clusters(users)
+        assert parent["attrs"] == {"requests": users.size, "unique": Bu,
+                                   "width": cl_p.size}
+        rows += Bu
+        padded += cl_p.size
+    c = tel.snapshot()["counters"]
+    assert c["serving.serve_calls"] == len(batches)
+    assert c["serving.serve_rows"] == rows
+    assert c["serving.serve_rows_padded"] == padded
+    lat = tel.snapshot()["hists"]["serving.retrieve_latency_s"]
+    assert lat["n"] == len(batches)
+    assert lat["sum"] == pytest.approx(sum(p["dur_s"] for p in parents))
+
+
+@pytest.mark.parametrize("delta_cap", [0, 16])
+def test_ingest_spans_nest_under_swap_and_store(delta_cap):
+    from repro.lifecycle.snapshot import IndexSnapshot
+    from repro.lifecycle.swap import SwapServer
+    rng = np.random.default_rng(22)
+    flat = _clusters(rng)
+    snap = IndexSnapshot(
+        user_codes=np.stack([flat // 3, flat % 3], 1),
+        item_codes=np.zeros((N_ITEMS, 2), np.int32), user_clusters=flat,
+        member_ptr=np.zeros(N_CLUSTERS + 1, np.int64),
+        member_ids=np.zeros(0, np.int64),
+        coarse_codebook=np.zeros((2, 4), np.float32),
+        i2i=np.zeros((N_ITEMS, 3), np.int64), version=1, n_users=N_USERS,
+        n_items=N_ITEMS, codebook_sizes=(2, 3))
+    tel = Telemetry()
+    srv = SwapServer(snap, queue_len=8, recency_s=1e9, telemetry=tel,
+                     delta_cap=delta_cap)
+    u = rng.integers(0, N_USERS, 40)
+    srv.ingest(u, rng.integers(0, N_ITEMS, 40), np.sort(rng.random(40)))
+    spans = tel.spans()
+    by = {s["name"]: s for s in spans}
+    assert by["swap.ingest"]["parent_id"] is None
+    assert by["swap.ingest"]["attrs"] == {"events": 40}
+    assert [k["name"] for k in _children(spans, by["swap.ingest"])] == \
+        ["swap.ring_push", "swap.drain"]
+    ing = by["serving.ingest"]
+    assert ing["parent_id"] == by["swap.drain"]["span_id"]
+    names = [k["name"] for k in _children(spans, ing)]
+    assert names[:2] == ["serving.ingest.lookup", "serving.ingest.prep"]
+    assert "serving.ingest.dispatch" in names
+    assert set(names) == {"serving.ingest.lookup", "serving.ingest.prep",
+                          "serving.ingest.dispatch"}
+    E = 40
+    width = 64 if delta_cap == 0 else 16 + 16 + 8
+    assert ing["attrs"] == {"events": E,
+                            "clusters": np.unique(flat[u]).size,
+                            "width": width}
+    # every span of the call is accounted for
+    assert {s["name"] for s in spans} == {
+        "swap.ingest", "swap.ring_push", "swap.drain", "serving.ingest",
+        "serving.ingest.lookup", "serving.ingest.prep",
+        "serving.ingest.dispatch"}
+
+
+def test_sharded_spans_hang_under_the_router_with_a_shard():
+    rng = np.random.default_rng(23)
+    flat = _clusters(rng)
+    tel = Telemetry()
+    shd = ShardedQueueStore(flat, n_shards=2, queue_len=8, recency_s=1e9,
+                            telemetry=tel)
+    _ingest_both((shd,), rng, n_batches=2)
+    i2i = rng.integers(0, N_ITEMS, (N_ITEMS, 3)).astype(np.int64)
+    shd.serve_batch(np.arange(N_USERS), 100.0, n_recent=4, k=8, i2i=i2i)
+    spans = tel.spans()
+    serve = [s for s in spans if s["name"] == "serving.serve_batch"]
+    assert len(serve) == 1 and serve[0]["parent_id"] is None
+    kids = _children(spans, serve[0])
+    assert sorted({k["attrs"]["shard"] for k in kids}) == [0, 1]
+    for shard in (0, 1):
+        assert [k["name"] for k in kids
+                if k["attrs"]["shard"] == shard] == SERVE_CHILDREN
+    for ing in [s for s in spans if s["name"] == "serving.ingest"]:
+        assert ing["parent_id"] is None
+        for k in _children(spans, ing):
+            assert k["name"].startswith("serving.ingest.")
+    shard_kids = [s for s in spans if "shard" in s["attrs"]]
+    assert {s["name"] for s in shard_kids} >= {
+        "serving.ingest.lookup", "serving.ingest.prep",
+        "serving.ingest.dispatch", *SERVE_CHILDREN}
+    c = tel.snapshot()["counters"]
+    assert c["serving.serve_calls"] == 1.0
+    assert c["serving.serve_rows"] == (c["serving.serve_rows.shard0"]
+                                       + c["serving.serve_rows.shard1"])
+
+
 def test_cost_model_shard_and_batch_scaling():
     """Launch overheads scale with the shard count and amortize with
     the dispatch batch; per-request queue work does neither."""
