@@ -268,12 +268,14 @@ def _retrieve_jit(st, cl, cutoff, k, C, Q, Deff):
 @functools.partial(jax.jit,
                    static_argnames=("n_recent", "k", "C", "Q", "Deff"))
 def _serve_jit(st, cl, i2i, cutoff, n_recent, k, C, Q, Deff):
+    """Seeds then union in one ``(B, n_recent + k)`` array, so a call
+    copies one buffer back to the host."""
     # the scopes name the device operations in a profile (metadata only)
     with jax.named_scope("serve.select"):
         cand, valid = _candidate_window(st, cl, cutoff, C, Q, Deff)
         seeds = _select_topk(cand, valid, n_recent)
     with jax.named_scope("serve.union"):
-        return seeds, _union_topk(seeds, i2i, k)
+        return jnp.concatenate([seeds, _union_topk(seeds, i2i, k)], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -370,11 +372,16 @@ class ClusterQueueStore:
         cluster 0 with ``known=False``; callers must mask their rows out
         rather than crash or serve another user's cluster.
         """
-        user_ids = np.asarray(user_ids, np.int64).ravel()
-        known = (user_ids >= 0) & (user_ids < self.user_clusters.shape[0])
-        cl = self.user_clusters[np.where(known, user_ids, 0)]
-        known = known & (cl >= 0)
+        cl, known = self._lookup(np.asarray(user_ids, np.int64).ravel())
         return np.where(known, cl, 0), known
+
+    def _lookup(self, user_ids: np.ndarray
+                ) -> Tuple[np.ndarray, np.ndarray]:
+        """Table entries of flat int64 ``user_ids`` and the known-user
+        mask; an entry means nothing where its user is not known."""
+        uc = self.user_clusters
+        cl = uc.take(user_ids, mode="clip")
+        return cl, (user_ids >= 0) & (user_ids < uc.shape[0]) & (cl >= 0)
 
     # -- ingestion ----------------------------------------------------------
 
@@ -575,15 +582,17 @@ class ClusterQueueStore:
     def _padded_clusters(self, user_ids: np.ndarray
                          ) -> Tuple[np.ndarray, np.ndarray, int,
                                     np.ndarray, np.ndarray]:
-        """Dedup a request batch down to its unique cluster rows (padded
-        to a power-of-two bucket) — most of a production batch shares
-        clusters, and broadcasting rows back via the inverse is exact."""
-        cl, known = self.clusters_of(user_ids)
+        """Dedup a request batch (flat int64 ids) down to its unique
+        cluster rows (padded with ``-1`` to a power-of-two bucket) — most
+        of a production batch shares clusters, and broadcasting rows back
+        via the inverse is exact.  Unknown users (as in ``clusters_of``)
+        get cluster ``-1``."""
+        cl, known = self._lookup(user_ids)
         cl = np.where(known, cl, -1)
         ucl, inv = np.unique(cl, return_inverse=True)
         Bu = ucl.size
-        cl_p = np.pad(ucl, (0, _bucket(Bu) - Bu),
-                      constant_values=-1).astype(np.int32)
+        cl_p = np.full(_bucket(Bu), -1, np.int32)
+        cl_p[:Bu] = ucl
         return cl_p, inv, Bu, cl, known
 
     def retrieve_batch(self, user_ids: np.ndarray, now: float,
@@ -665,10 +674,12 @@ class ClusterQueueStore:
 
         The fused path's span ``serving.serve_batch`` (attrs
         ``requests``, ``unique``, ``width``) has four children:
-        ``serving.serve.prep`` (cluster lookup, dedup, padding, the copy
-        of the cluster ids to the device), ``.dispatch`` (the enqueue of
-        ``_serve_jit``), ``.fetch`` (the wait for the device and the copy
-        back) and ``.expand`` (rows back in request order).  Under a
+        ``serving.serve.prep`` (host only: cluster lookup, dedup, padding,
+        the cutoff), ``.dispatch`` (the upload of the cluster ids and the
+        cutoff and the enqueue of ``_serve_jit``, in one launch),
+        ``.fetch`` (the wait for the device and the one copy back of
+        seeds and union together) and ``.expand`` (rows back in request
+        order; seeds and union are column slices of one array).  Under a
         router the router holds the parent and the children carry
         ``shard``."""
         if i2i is not None and use_kernel:
@@ -719,17 +730,18 @@ class ClusterQueueStore:
         tel, kw = self.tel, self._span_kw
         with tel.hot_span("serving.serve.prep", **kw) as prep:
             cl_p, inv, Bu, _, known = self._padded_clusters(user_ids)
-            cl_dev = jnp.asarray(cl_p)
+            # rounded as jnp.float32 rounds it; numpy arguments are
+            # uploaded by the jit's own launch, with no program of their own
+            cutoff = np.float32(self.rel_cutoff(now))
         with tel.hot_span("serving.serve.dispatch", **kw) as dispatch:
-            s, u = _serve_jit(self._state, cl_dev, self._i2i_device(i2i),
-                              jnp.float32(self.rel_cutoff(now)),
-                              int(n_recent), int(k), self.n_clusters,
-                              self.queue_len, self.delta_cap)
+            out = _serve_jit(self._state, cl_p, self._i2i_device(i2i),
+                             cutoff, int(n_recent), int(k), self.n_clusters,
+                             self.queue_len, self.delta_cap)
         with tel.hot_span("serving.serve.fetch", **kw) as fetch:
-            s, u = np.asarray(s), np.asarray(u)
+            out = np.asarray(out)
         with tel.hot_span("serving.serve.expand", **kw) as expand:
-            seeds = s[:Bu][inv].astype(np.int64)
-            union = u[:Bu][inv].astype(np.int64)
+            rows = out[:Bu][inv].astype(np.int64)
+        seeds, union = rows[:, :n_recent], rows[:, n_recent:]
         if tel.enabled:
             width = int(cl_p.size)
             if sp is not None:

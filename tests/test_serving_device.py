@@ -148,10 +148,29 @@ def test_ts_regression_cross_batch_is_the_documented_tolerance():
                                   host.retrieve_batch(u, 61.0, 4))
 
 
-def _ingest_both(stores, rng, n_batches=5):
-    for u, it, ts in _batches(rng, n_batches):
+def _ingest_both(stores, rng, n_batches=5, id_hi=N_USERS + 4):
+    for u, it, ts in _batches(rng, n_batches, id_hi=id_hi):
         for s in stores:
             s.ingest(u, it, ts)
+
+
+N_WIDE_USERS, N_WIDE_CLUSTERS = 400, 100
+
+
+def _wide_population(rng):
+    """A user table over 100 clusters and request batches whose unique
+    cluster rows pad to every width bucket from 8 to 128, each with a
+    repeated user, a post-snapshot id and a negative id."""
+    flat = rng.integers(0, N_WIDE_CLUSTERS, N_WIDE_USERS).astype(np.int64)
+    one_per_cluster = np.unique(flat, return_index=True)[1]
+    extra = np.array([one_per_cluster[0], N_WIDE_USERS + 3, -1])
+    batches = [np.concatenate([one_per_cluster[:n], extra])
+               for n in (5, 12, 24, 48, 90)]
+    return flat, batches
+
+
+def _wide_ingest(stores, rng):
+    _ingest_both(stores, rng, n_batches=30, id_hi=N_WIDE_USERS + 4)
 
 
 def test_fused_serve_matches_host_u2i2i():
@@ -176,6 +195,126 @@ def test_fused_serve_matches_host_u2i2i():
     seeds, union = dev.serve_batch(_PROBES, 100.0, n_recent=4, k=8)
     np.testing.assert_array_equal(seeds, hs)
     assert (union == -1).all()
+    # every width bucket from 8 to 128, unknown users included
+    flat, batches = _wide_population(rng)
+    dev = ClusterQueueStore(flat, queue_len=8, recency_s=1e9)
+    shd = ShardedQueueStore(flat, n_shards=2, queue_len=8, recency_s=1e9)
+    host = HostQueueStore(flat, queue_len=8, recency_s=1e9)
+    _wide_ingest((dev, shd, host), rng)
+    widths = set()
+    for users in batches:
+        hs, hu = host.serve_batch(users, 100.0, n_recent=4, k=8, i2i=i2i)
+        assert (hs >= 0).any()
+        for store in (dev, shd):
+            seeds, union = store.serve_batch(users, 100.0, n_recent=4,
+                                             k=8, i2i=i2i)
+            assert seeds.dtype == union.dtype == np.int64
+            np.testing.assert_array_equal(seeds, hs)
+            np.testing.assert_array_equal(union, hu)
+            np.testing.assert_array_equal(
+                union, u2i2i_retrieve_batch(i2i, seeds, 8))
+        widths.add(dev._padded_clusters(users)[0].size)
+    assert widths == {8, 16, 32, 64, 128}
+
+
+# ---------------------------------------------------------------------------
+# launches and compiles per serve call
+# ---------------------------------------------------------------------------
+
+def _serve_store(kind, flat):
+    if kind == "sharded":
+        return ShardedQueueStore(flat, n_shards=2, queue_len=8,
+                                 recency_s=1e9)
+    return ClusterQueueStore(flat, queue_len=8, recency_s=1e9)
+
+
+def _stores_serving(store, users):
+    """How many stores of ``store`` serve rows of ``users``: an
+    unsharded store serves every batch, a router only the shards that
+    own a known user's cluster."""
+    if isinstance(store, ClusterQueueStore):
+        return 1
+    sid = store._shard_of(*store.clusters_of(users))
+    return np.unique(sid[sid >= 0]).size
+
+
+@pytest.fixture
+def launches(monkeypatch):
+    """Counts of ``_serve_jit`` calls, of their per-call arguments (the
+    cluster ids and the cutoff) that arrive as device arrays, that is
+    uploaded by a call of their own, and of eager single-primitive
+    dispatches such as ``jnp.float32(x)``.
+
+    ``dispatch.apply_primitive`` is bound into each primitive's impl by
+    ``functools.partial`` when JAX is imported, so a wrapper of that
+    module attribute is never called; ``apply_primitive`` looks up
+    ``xla_primitive_callable`` in its module on every call, so the count
+    is taken there.  The fixture proves that the counter sees an eager
+    conversion before any test relies on it reading 0."""
+    import jax.numpy as jnp
+    from jax._src import dispatch
+    import repro.core.serving as serving
+    n = {"serve_jit": 0, "uploads": 0, "primitive": 0}
+    serve_jit, prim = serving._serve_jit, dispatch.xla_primitive_callable
+
+    def counted_serve_jit(st, cl, i2i, cutoff, *args, **kw):
+        n["serve_jit"] += 1
+        n["uploads"] += sum(isinstance(a, jax.Array) for a in (cl, cutoff))
+        return serve_jit(st, cl, i2i, cutoff, *args, **kw)
+
+    def counted_prim(*args, **kw):
+        n["primitive"] += 1
+        return prim(*args, **kw)
+
+    monkeypatch.setattr(serving, "_serve_jit", counted_serve_jit)
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", counted_prim)
+    jnp.float32(1.0)
+    assert n["primitive"] == 1
+    n["primitive"] = 0
+    return n
+
+
+@pytest.mark.parametrize("kind", ["unsharded", "sharded"])
+def test_serve_batch_is_one_launch_per_store(kind, launches):
+    """One ``serve_batch`` is one ``_serve_jit`` launch per store that
+    serves rows, and no other program or upload: the cluster ids and the
+    cutoff go into the jit as numpy values."""
+    rng = np.random.default_rng(31)
+    flat = _clusters(rng)
+    store = _serve_store(kind, flat)
+    _ingest_both((store,), rng)
+    i2i = rng.integers(0, N_ITEMS, (N_ITEMS, 3)).astype(np.int64)
+    # the first call uploads the I2I table (once a swap) and compiles
+    store.serve_batch(np.array([0]), 100.0, n_recent=4, k=8, i2i=i2i)
+    served = 0
+    for users in (np.array([0]), np.array([N_USERS + 2]), _PROBES,
+                  np.arange(N_USERS)):
+        store.serve_batch(users, 100.0, n_recent=4, k=8, i2i=i2i)
+        launches.update(serve_jit=0, uploads=0, primitive=0)
+        store.serve_batch(users, 100.0, n_recent=4, k=8, i2i=i2i)
+        assert launches == {"serve_jit": _stores_serving(store, users),
+                            "uploads": 0, "primitive": 0}
+        served += launches["serve_jit"]
+    assert served >= 4
+
+
+@pytest.mark.parametrize("kind", ["unsharded", "sharded"])
+def test_serve_width_compiles_once(kind):
+    """A second call at a padded width already served adds no entry to
+    ``_serve_jit``'s cache: warm-up and the window pass the same kinds
+    of argument, so the window compiles nothing."""
+    from repro.core.serving import _serve_jit
+    rng = np.random.default_rng(32)
+    flat = _clusters(rng)
+    store = _serve_store(kind, flat)
+    _ingest_both((store,), rng)
+    i2i = rng.integers(0, N_ITEMS, (N_ITEMS, 3)).astype(np.int64)
+    for first, second in ((np.array([0]), np.array([5])),
+                          (_PROBES, np.arange(N_USERS))):
+        store.serve_batch(first, 100.0, n_recent=4, k=8, i2i=i2i)
+        before = _serve_jit._cache_size()
+        store.serve_batch(second, 150.0, n_recent=4, k=8, i2i=i2i)
+        assert _serve_jit._cache_size() == before
 
 
 def test_kernel_serve_path_matches_fused():
@@ -264,26 +403,35 @@ def test_serve_batch_spans_and_counters_agree_with_padding():
     dev = ClusterQueueStore(flat, queue_len=8, recency_s=1e9,
                             telemetry=tel)
     _ingest_both((dev,), rng)
+    wide_flat, wide_batches = _wide_population(rng)
+    wide = ClusterQueueStore(wide_flat, queue_len=8, recency_s=1e9,
+                             telemetry=tel)
+    _wide_ingest((wide,), rng)
     i2i = rng.integers(0, N_ITEMS, (N_ITEMS, 3)).astype(np.int64)
     tel.reset_spans()
     tel.reset_metrics()
-    batches = [_PROBES, np.arange(N_USERS), np.array([3])]
-    for users in batches:
-        dev.serve_batch(users, 100.0, n_recent=4, k=8, i2i=i2i)
+    batches = [(dev, users)
+               for users in (_PROBES, np.arange(N_USERS), np.array([3]))]
+    batches += [(wide, users) for users in wide_batches]
+    for store, users in batches:
+        store.serve_batch(users, 100.0, n_recent=4, k=8, i2i=i2i)
     spans = tel.spans()
     parents = [s for s in spans if s["name"] == "serving.serve_batch"]
     assert len(parents) == len(batches)
     rows = padded = 0
-    for users, parent in zip(batches, parents):
+    widths = set()
+    for (store, users), parent in zip(batches, parents):
         assert parent["parent_id"] is None
         kids = _children(spans, parent)
         assert [k["name"] for k in kids] == SERVE_CHILDREN
         assert sum(k["dur_s"] for k in kids) <= parent["dur_s"]
-        cl_p, _, Bu, _, _ = dev._padded_clusters(users)
+        cl_p, _, Bu, _, _ = store._padded_clusters(users)
         assert parent["attrs"] == {"requests": users.size, "unique": Bu,
                                    "width": cl_p.size}
         rows += Bu
         padded += cl_p.size
+        widths.add(cl_p.size)
+    assert widths == {8, 16, 32, 64, 128}
     c = tel.snapshot()["counters"]
     assert c["serving.serve_calls"] == len(batches)
     assert c["serving.serve_rows"] == rows
